@@ -26,25 +26,10 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import HorstConfig, cca_objective, horst_cca
 from repro.core.rcca import RCCAConfig, randomized_cca_iterator
-from repro.data import HashingFeaturizer
-
-
-def synth_paired_docs(n, vocab=50_000, doc_len=30, seed=0):
-    """Paired 'translations': view B's tokens are a deterministic map of
-    view A's plus noise — so the views share latent structure exactly
-    like sentence-aligned Europarl."""
-    rng = np.random.default_rng(seed)
-    # zipfian-ish token draws
-    base = rng.zipf(1.3, size=(n, doc_len)).clip(1, vocab - 1)
-    translate = lambda t: (t * 2_654_435_761) % vocab + 1  # fixed "dictionary"
-    noise_mask = rng.random((n, doc_len)) < 0.2
-    other = rng.zipf(1.3, size=(n, doc_len)).clip(1, vocab - 1)
-    paired = np.where(noise_mask, other, translate(base))
-    return base.astype(np.int64), paired.astype(np.int64)
+from repro.data import HashingFeaturizer, synth_paired_docs
 
 
 def main():

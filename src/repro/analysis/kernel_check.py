@@ -22,7 +22,12 @@ device, no kernel execution):
            silently published to HBM.
   RCCA104  VMEM residency: every block and scratch buffer fits the
            shared per-buffer budget
-           (:data:`repro.kernels.matmul.VMEM_BLOCK_ELEMS`).
+           (:data:`repro.kernels.matmul.VMEM_BLOCK_ELEMS`); the whole
+           charge (:attr:`KernelPlan.vmem_need_bytes`: pipeline
+           buffers, body values, slack) fits the scoped limit that
+           :func:`repro.kernels.plan.launch_args` requests for the plan
+           (Mosaic's 16 MiB default when it requests none); and that
+           limit stays under :data:`repro.kernels.plan.VMEM_LIMIT_CAP`.
   RCCA105  dtype rules: scratch accumulators and declared accumulator
            outputs are f32; bf16 inputs never accumulate in bf16.
   RCCA106  abstract-eval agreement: ``jax.eval_shape`` of the live
@@ -58,6 +63,8 @@ def _probe_tag(name: str, probe: dict) -> str:
 def check_plan(plan, *, where: str = "", budget: Optional[int] = None) -> List[Violation]:
     """RCCA101–105 on one :class:`~repro.kernels.plan.KernelPlan`."""
     from repro.kernels.matmul import VMEM_BLOCK_ELEMS
+    from repro.kernels.plan import (MOSAIC_DEFAULT_VMEM_LIMIT,
+                                    VMEM_LIMIT_CAP, launch_args)
 
     budget = VMEM_BLOCK_ELEMS if budget is None else budget
     where = where or plan.name
@@ -136,6 +143,15 @@ def check_plan(plan, *, where: str = "", budget: Optional[int] = None) -> List[V
         if s.elems > budget:
             v("RCCA104", f"scratch[{i}]: {s.shape} = {s.elems} elems "
               f"exceeds VMEM budget {budget}")
+    limit = launch_args(plan)["compiler_params"].vmem_limit_bytes
+    limit = MOSAIC_DEFAULT_VMEM_LIMIT if limit is None else limit
+    if plan.vmem_need_bytes > limit:
+        v("RCCA104", f"double-buffered blocks + scratch = {plan.vmem_bytes} "
+          f"bytes, charged {plan.vmem_need_bytes} with the body's values, "
+          f"exceed the scoped VMEM limit {limit} its launch requests")
+    if limit > VMEM_LIMIT_CAP:
+        v("RCCA104", f"requested scoped VMEM limit {limit} bytes exceeds "
+          f"the cap {VMEM_LIMIT_CAP}")
 
     # -- RCCA105: dtype rules ---------------------------------------------
     for i, s in enumerate(plan.scratch):

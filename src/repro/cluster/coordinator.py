@@ -11,7 +11,7 @@ claim as a subsystem): for each of the q+1 data passes the coordinator
    with ``devices_per_worker > 1`` each worker folds its merge groups
    one-per-device over a local mesh (the HYBRID topology — the spawner
    forces ``--xla_force_host_platform_device_count`` into the worker
-   environment so the layout works on accelerator-less hosts too),
+   environment, a CPU rehearsal of the layout),
 3. runs the BARRIER: polls for per-merge-group partials, re-dispatching
    the merge groups of dead, stale-heartbeat or straggling workers to
    fresh repair workers (at-most-once per group id — duplicates are
@@ -30,6 +30,12 @@ on the same store for any worker count AND any devices-per-worker
 layout (tests/test_cluster.py, tests/test_exec_topologies.py), under
 injected worker kills (tests/test_cluster_failures.py) and injected
 worker hangs caught by the heartbeat monitor.
+
+One process per chip: a TPU belongs to the first process that opens
+it, and the coordinator opens its default backend for the bases and the
+merge.  On a TPU host its workers could then never reach a chip, so the
+coordinator refuses to start there (fit with ``Local`` or ``Sharded``,
+or rehearse the cluster on the host's CPU with ``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
@@ -79,9 +85,9 @@ class ClusterCoordinator:
     n_workers:      worker processes per pass.
     devices_per_worker: local devices each worker folds merge groups
                     over (>1 = the Hybrid topology; workers are spawned
-                    with the forced-host-device XLA flag so the layout
-                    runs on any host).  Results are bitwise invariant
-                    to this knob.
+                    with the forced-host-device XLA flag, a CPU
+                    rehearsal of the layout).  Results are bitwise
+                    invariant to this knob.
     engine:         data-pass engine, binding for every partial.
     merge_group:    chunks per merge group (the partial granularity).
                     MUST equal the single-process driver's value for
@@ -159,6 +165,14 @@ class ClusterCoordinator:
                 f"combine_groups must be a power of two (a combined span "
                 f"must be one subtree of the canonical pairwise "
                 f"reduction), got {self.combine_groups}")
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "ClusterCoordinator cannot run on a TPU host: this process "
+                "holds the TPU once it computes the bases, and a chip "
+                "serves one process at a time, so its worker processes "
+                "could never open one.  Fit with the Local or Sharded "
+                "topology, or rehearse the cluster on the CPU with "
+                "JAX_PLATFORMS=cpu.")
         os.makedirs(os.path.join(cluster_dir, "logs"), exist_ok=True)
         # (pass_idx, group) → error for stale-partial removals that
         # failed — surfaced in diagnostics, retried at every pass sweep
@@ -192,8 +206,8 @@ class ClusterCoordinator:
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         if self.devices_per_worker > 1:
             # hybrid workers need their device mesh before jax wakes up;
-            # on accelerator hosts the flag is inert (it only forces the
-            # HOST platform's device count)
+            # the flag forces the CPU platform's device count, so hybrid
+            # is a CPU rehearsal (TPU hosts are refused in __init__)
             flag = ("--xla_force_host_platform_device_count="
                     f"{self.devices_per_worker}")
             env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()

@@ -75,6 +75,7 @@ from repro.core.rcca import (jit_seeded_update_fn, jit_update_fn,
                              seeded_update_fn, stats_init_fn, update_fn)
 from repro.exec import (SegmentedAccumulator, SpanCombiner,
                         fold_groups_on_mesh, n_full_chunks, run_fold)
+from repro.launch.compile_cache import use_compile_cache
 from repro.store import ViewStoreReader, prefetched, shard_chunks
 
 from . import partials as pt
@@ -337,6 +338,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=4)
     ap.add_argument("--round-wait-s", type=float, default=30.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
     groups = None
     if args.groups:
         groups = [int(g) for g in args.groups.split(",")]

@@ -1,6 +1,7 @@
 """Core contribution of the paper: RandomizedCCA and its baselines."""
 
-from .exact import CCASolution, cca_objective, exact_cca, feasibility_errors
+from .exact import (CCASolution, cca_objective, exact_cca, feasibility_errors,
+                    streamed_feasibility_errors)
 from .horst import HorstConfig, HorstResult, horst_cca
 from .rcca import (
     RCCAConfig,
@@ -15,6 +16,7 @@ __all__ = [
     "cca_objective",
     "exact_cca",
     "feasibility_errors",
+    "streamed_feasibility_errors",
     "HorstConfig",
     "HorstResult",
     "horst_cca",

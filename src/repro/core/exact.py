@@ -16,7 +16,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .linalg import inv_sqrt_psd, sym, topk_svd
+from .linalg import full_f32, inv_sqrt_psd, sym, topk_svd
 
 
 class CCASolution(NamedTuple):
@@ -29,6 +29,7 @@ def center(M: jax.Array) -> jax.Array:
     return M - jnp.mean(M, axis=0, keepdims=True)
 
 
+@full_f32
 def exact_cca(
     A: jax.Array,
     B: jax.Array,
@@ -57,6 +58,7 @@ def exact_cca(
     return CCASolution(Xa=Xa, Xb=Xb, rho=S)
 
 
+@full_f32
 def cca_objective(A: jax.Array, B: jax.Array, Xa: jax.Array, Xb: jax.Array) -> jax.Array:
     """(1/n) Tr(Xaᵀ AᵀB Xb) — the quantity in paper Fig. 2a / Table 2b."""
     n = A.shape[0]
@@ -65,6 +67,7 @@ def cca_objective(A: jax.Array, B: jax.Array, Xa: jax.Array, Xb: jax.Array) -> j
     return jnp.trace(PA.T @ PB) / n
 
 
+@full_f32
 def feasibility_errors(
     A: jax.Array,
     B: jax.Array,
@@ -75,12 +78,27 @@ def feasibility_errors(
 ) -> dict[str, jax.Array]:
     """Constraint residuals: paper reports solutions feasible to machine
     precision — (regularized) identity covariance & diagonal cross-cov."""
-    n = A.shape[0]
-    k = Xa.shape[1]
-    Ia = Xa.T @ (A.T @ (A @ Xa)) + lam_a * (Xa.T @ Xa)
-    Ib = Xb.T @ (B.T @ (B @ Xb)) + lam_b * (Xb.T @ Xb)
-    C = Xa.T @ (A.T @ (B @ Xb)) / n
-    eye = jnp.eye(k, dtype=Xa.dtype)
+    return streamed_feasibility_errors([(A, B)], Xa, Xb, lam_a, lam_b)
+
+
+@full_f32
+def streamed_feasibility_errors(chunks, Xa, Xb, lam_a=0.0,
+                                lam_b=0.0) -> dict[str, jax.Array]:
+    """:func:`feasibility_errors` over an iterable of paired row chunks
+    ``(a, b)``: (AXa)ᵀ(AXa) = Σ (aXa)ᵀ(aXa), so only k × k Grams are
+    accumulated and A, B are never materialized."""
+    n = 0
+    Ga = Gb = C = 0.0
+    for a, b in chunks:
+        pa, pb = a @ Xa, b @ Xb
+        Ga = Ga + pa.T @ pa
+        Gb = Gb + pb.T @ pb
+        C = C + pa.T @ pb
+        n += a.shape[0]
+    Ia = Ga + lam_a * (Xa.T @ Xa)
+    Ib = Gb + lam_b * (Xb.T @ Xb)
+    C = C / n
+    eye = jnp.eye(Xa.shape[1], dtype=Xa.dtype)
     offdiag = C - jnp.diag(jnp.diagonal(C))
     return {
         "cov_a": jnp.max(jnp.abs(Ia / n - eye)),

@@ -18,7 +18,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .linalg import inv_sqrt_psd, sym
+from .linalg import full_f32, inv_sqrt_psd, sym
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +68,7 @@ def _cg_solve(M_mul, RHS: jax.Array, iters: int) -> jax.Array:
     return X
 
 
+@full_f32
 def horst_cca(
     A: jax.Array,
     B: jax.Array,
@@ -195,6 +196,7 @@ class StreamingGrams:
         return U, V
 
 
+@full_f32
 def horst_cca_streaming(
     source_factory,
     da: int,

@@ -9,9 +9,27 @@ sense (§3: feasible on one commodity machine for k+p ≲ 10000).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
+
+
+def full_f32(fn):
+    """Run ``fn`` with its matmuls at full f32 precision.
+
+    On a TPU, XLA's default f32 matmul is one bf16 pass (~4e-3 relative
+    error), which the fit's whitening amplifies far past its tolerances.
+    The scope covers every matmul ``fn`` traces or dispatches; the
+    Pallas kernels set their own precision (``kernels.matmul.mxu_dot``).
+    A CPU computes f32 matmuls in f32 either way, so there it changes
+    nothing."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 def sym(M: jax.Array) -> jax.Array:

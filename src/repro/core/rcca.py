@@ -35,7 +35,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .linalg import orth, sym, topk_svd, tri_solve_right
+from .linalg import full_f32, orth, sym, topk_svd, tri_solve_right
 from jax.scipy.linalg import solve_triangular
 
 
@@ -160,6 +160,7 @@ def init_final_stats(sketch: int, da: int, db: int, dtype) -> FinalStats:
     )
 
 
+@full_f32
 def update_power_stats(
     s: PowerStats, a: jax.Array, b: jax.Array, Qa: jax.Array, Qb: jax.Array
 ) -> PowerStats:
@@ -183,6 +184,7 @@ def update_power_stats(
     )
 
 
+@full_f32
 def update_power_stats_kernel(
     s: PowerStats, a: jax.Array, b: jax.Array, Qa: jax.Array, Qb: jax.Array
 ) -> PowerStats:
@@ -206,6 +208,7 @@ def update_power_stats_kernel(
     )
 
 
+@full_f32
 def update_final_stats_kernel(
     s: FinalStats, a: jax.Array, b: jax.Array, Qa: jax.Array, Qb: jax.Array
 ) -> FinalStats:
@@ -227,6 +230,7 @@ def update_final_stats_kernel(
     )
 
 
+@full_f32
 def update_final_stats(
     s: FinalStats, a: jax.Array, b: jax.Array, Qa: jax.Array, Qb: jax.Array
 ) -> FinalStats:
@@ -375,12 +379,14 @@ def init_Q(key: jax.Array, da: int, db: int, cfg: RCCAConfig,
             krand.dense_omega(seed_b, db, cfg.sketch, cfg.dtype))
 
 
+@full_f32
 def power_update_Q(stats: PowerStats, Qa, Qb, cfg: RCCAConfig):
     """Lines 10-11: close one range-finder pass (center + orth)."""
     Ya, Yb = centered_Y(stats, Qa, Qb, cfg.center)
     return orth(Ya.astype(cfg.dtype)), orth(Yb.astype(cfg.dtype))
 
 
+@full_f32
 def finalize_result(fstats: FinalStats, Qa, Qb, cfg: RCCAConfig,
                     da: int, db: int) -> RCCAResult:
     """Lines 19-25 from merged final-pass statistics."""
@@ -439,6 +445,7 @@ def finish(
 # --------------------------------------------------------------------------
 
 
+@full_f32
 def randomized_cca(
     A: jax.Array, B: jax.Array, cfg: RCCAConfig, key: jax.Array
 ) -> RCCAResult:
@@ -550,6 +557,7 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
 
     f32 = jnp.float32
     if kind == "power":
+        @full_f32
         def upd(s: PowerStats, a, b, seed_a, seed_b) -> PowerStats:
             dYa, dYb = kops.power_pass_chunk_seeded(a, b, seed_a, seed_b,
                                                     kt=kt, q_dtype=q_dtype)
@@ -564,6 +572,7 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
             )
         return upd
     if kind == "final":
+        @full_f32
         def upd(s: FinalStats, a, b, seed_a, seed_b) -> FinalStats:
             dCa, dCb, dF = kops.final_pass_chunk_seeded(a, b, seed_a, seed_b,
                                                         kt=kt, q_dtype=q_dtype)

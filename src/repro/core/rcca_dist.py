@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.kernels.compat import shard_map
 
-from .linalg import sym, tri_solve_right
+from .linalg import full_f32, sym, tri_solve_right
 from .rcca import DEFAULT_ENGINE, RCCAConfig, RCCAResult, finish, resolve_engine
 from repro.exec.engine import pass_schedule
 
@@ -47,6 +47,14 @@ def _psum(x, axes):
     if isinstance(axes, str):
         axes = (axes,)
     return jax.lax.psum(x, tuple(axes))
+
+
+def _trace_psum(tra, trb, row_axes, col_axis):
+    """‖A‖²_F and ‖B‖²_F from local shards: every row shard and every
+    feature shard holds a part of the sum (λ = ν·tr/d needs the whole;
+    a per-feature-shard λ whitens each shard of X differently)."""
+    axes = tuple(row_axes) + ((col_axis,) if col_axis is not None else ())
+    return _psum(tra, axes), _psum(trb, axes)
 
 
 def dist_orth(Y: jax.Array, col_axis: Optional[str]):
@@ -221,8 +229,8 @@ def power_pass_local(a, b, Qa, Qb, *, row_axes, col_axis, microbatch=None,
 
     Ya, Yb = reduce_Y(Ya), reduce_Y(Yb)
     sa, sb = (_psum(t, row_axes) for t in (sa, sb))
-    tra, trb, n = (_psum(t, row_axes) for t in (tra, trb, n))
-    return Ya, Yb, sa, sb, tra, trb, n
+    tra, trb = _trace_psum(tra, trb, row_axes, col_axis)
+    return Ya, Yb, sa, sb, tra, trb, _psum(n, row_axes)
 
 
 def final_pass_local(a, b, Qa, Qb, *, row_axes, col_axis, microbatch=None,
@@ -315,8 +323,8 @@ def final_pass_local(a, b, Qa, Qb, *, row_axes, col_axis, microbatch=None,
     # over col_axis) — reduce over rows only.
     Ca, Cb, F = (_psum(t, row_axes) for t in (Ca, Cb, F))
     sa, sb = (_psum(t, row_axes) for t in (sa, sb))
-    tra, trb, n = (_psum(t, row_axes) for t in (tra, trb, n))
-    return Ca, Cb, F, sa, sb, tra, trb, n
+    tra, trb = _trace_psum(tra, trb, row_axes, col_axis)
+    return Ca, Cb, F, sa, sb, tra, trb, _psum(n, row_axes)
 
 
 # --------------------------------------------------------------------------
@@ -324,6 +332,7 @@ def final_pass_local(a, b, Qa, Qb, *, row_axes, col_axis, microbatch=None,
 # --------------------------------------------------------------------------
 
 
+@full_f32
 def dist_randomized_cca(
     A: jax.Array,
     B: jax.Array,

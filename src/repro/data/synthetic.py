@@ -7,6 +7,10 @@ is chunked and deterministic per chunk index → the stream can be
 replayed from any point (fault-tolerant data passes) and sharded by
 row-range across workers without materializing n × d in memory.
 
+synth_paired_docs — the paper's bilingual corpus as token bags: about
+30 Zipf(1.3) tokens per row, view B a fixed "translation" of view A with
+20% noise, ready for :class:`repro.data.HashingFeaturizer`.
+
 SyntheticTokenStream — deterministic LM token batches for train steps.
 """
 
@@ -102,3 +106,19 @@ def planted_views(key_seed: int, n: int, da: int, db: int, rank: int = 8,
     d = PlantedCCAData(n=n, da=da, db=db, rank=rank, decay=decay, noise=noise,
                        seed=key_seed, chunk=max(256, n // 8))
     return d.materialize()
+
+
+def synth_paired_docs(n: int, vocab: int = 50_000, doc_len: int = 30,
+                      seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
+    """Paired 'translations': view B's tokens are a deterministic map of
+    view A's plus noise — so the views share latent structure exactly
+    like sentence-aligned Europarl.  Returns two (n, doc_len) int64
+    token-id matrices (ids ≥ 1; 0 is the featurizer's pad id)."""
+    rng = np.random.default_rng(seed)
+    # zipfian-ish token draws
+    base = rng.zipf(1.3, size=(n, doc_len)).clip(1, vocab - 1)
+    translate = lambda t: (t * 2_654_435_761) % vocab + 1  # fixed "dictionary"
+    noise_mask = rng.random((n, doc_len)) < 0.2
+    other = rng.zipf(1.3, size=(n, doc_len)).clip(1, vocab - 1)
+    paired = np.where(noise_mask, other, translate(base))
+    return base.astype(np.int64), paired.astype(np.int64)

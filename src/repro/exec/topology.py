@@ -106,8 +106,9 @@ class Hybrid:
     """Row parallelism across worker processes × group parallelism
     across each worker's local device mesh.  Workers are spawned with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=
-    devices_per_worker`` on hosts without real accelerators, so the
-    layout is exercisable anywhere."""
+    devices_per_worker``, which forces the CPU platform's device count:
+    the layout is a CPU rehearsal.  The coordinator refuses TPU hosts,
+    where one process per chip leaves no chip for the workers."""
 
     n_workers: int = 2
     devices_per_worker: int = 4

@@ -1,67 +1,59 @@
-"""Single jax-version shim for the data-pass engine.
+"""The jax surface the data-pass engine reaches through one module.
 
-jax renames a handful of names the kernel and launch layers depend on;
-every version-specific spelling is resolved HERE, once, so
-``matmul.py``, ``projgram.py``, ``powerpass.py`` and the launch drivers
-never touch them directly:
+Pinned to the installed jax (0.9.0).  The kernel and launch layers
+touch the version-sensitive namespaces only through these helpers:
 
-- ``tpu_compiler_params(...)`` — ``pltpu.CompilerParams`` (jax ≥ 0.5)
-  vs ``pltpu.TPUCompilerParams`` (jax 0.4.x).
-- ``set_mesh(mesh)`` — context manager making ``mesh`` ambient:
-  ``jax.set_mesh`` (jax ≥ 0.5) vs the ``with mesh:`` thread-resources
-  context (jax 0.4.x).
-- ``cost_analysis(compiled)`` — dict (jax ≥ 0.5) vs single-element
-  list of dicts (jax 0.4.x).
-- ``count_pallas_calls(jaxpr)`` — recursive jaxpr walk over
-  ``jax.core`` containers (the fused-vs-fallback regression metric
-  used by tests and benchmarks; jaxpr internals move between jax
-  versions, so the walk lives here).
-- ``vmem(shape, dtype)`` — a VMEM scratch allocation
-  (``pltpu.VMEM``); the ``pltpu`` namespace itself is the
-  version-sensitive surface, so kernel modules go through this helper.
+- ``tpu_compiler_params(...)`` — ``pltpu.CompilerParams``.
+- ``set_mesh(mesh)`` — ``jax.set_mesh``: makes ``mesh`` ambient.
+- ``cost_analysis(compiled)`` — ``compiled.cost_analysis()`` as a
+  (possibly empty) dict.
+- ``sub_jaxprs(value)`` / ``count_pallas_calls(jaxpr)`` — the jaxpr walk
+  over ``jax.extend.core`` containers (the fused-vs-fallback regression
+  metric used by tests and benchmarks).
+- ``vmem(shape, dtype)`` — a VMEM scratch allocation (``pltpu.VMEM``).
 - ``smem_spec()`` — a ``pl.BlockSpec`` placing a small scalar operand
-  (e.g. the Ω PRNG seed) in SMEM (``pltpu.SMEM``), the scalar-operand
-  path for the seeded kernels.
+  (e.g. the Ω PRNG seed) in SMEM, the scalar-operand path for the
+  seeded kernels.
 - ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_rep=...)``
-  — ``jax.shard_map`` (jax ≥ 0.6, where ``check_rep`` became
-  ``check_vma``) vs ``jax.experimental.shard_map.shard_map``.
+  — ``jax.shard_map`` with ``check_rep`` passed as ``check_vma``.
 
 ``repro.analysis`` lint rule RCCA002 enforces the discipline: no
 ``pltpu.`` / ``jax.experimental.shard_map`` use outside this module.
 
-Both helpers resolve the spelling at call time (not import time) so a
-jax upgrade — or a test monkeypatching one spelling — is picked up
-without reloading this module.
+The helpers resolve their jax attribute at call time (not import time),
+so a test monkeypatching one is picked up without reloading this module.
 """
 
 from __future__ import annotations
-
-import contextlib
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
 
 def tpu_compiler_params(*, dimension_semantics=None, **kwargs):
-    """Build Mosaic compiler params under either jax spelling.
-
-    Accepts the keywords shared by both classes (``dimension_semantics``,
-    ``vmem_limit_bytes``, ...) and returns an instance suitable for
-    ``pl.pallas_call(compiler_params=...)``.
-    """
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics, **kwargs)
+    """Mosaic compiler params (``dimension_semantics``,
+    ``vmem_limit_bytes``, ...) for ``pl.pallas_call(compiler_params=...)``."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                **kwargs)
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalized ``compiled.cost_analysis()`` — always a (possibly
-    empty) dict, whichever container this jax returns."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()`` — always a (possibly empty) dict."""
+    return compiled.cost_analysis() or {}
+
+
+def sub_jaxprs(value):
+    """Yield the jaxprs held by one eqn parameter value: a closed or
+    open jaxpr, or a list/tuple of them (``cond`` branches)."""
+    from jax.extend import core
+
+    if isinstance(value, core.ClosedJaxpr):
+        yield value.jaxpr
+    elif isinstance(value, core.Jaxpr):
+        yield value
+    elif isinstance(value, (list, tuple)):
+        for v in value:
+            yield from sub_jaxprs(v)
 
 
 def count_pallas_calls(closed_jaxpr) -> int:
@@ -70,7 +62,6 @@ def count_pallas_calls(closed_jaxpr) -> int:
     on (2 fused calls per power-pass chunk; a fallback to the unfused
     matmul pair doubles it).  It counts kernel launches, not HBM
     traffic — bucketed grids re-read inputs within one call."""
-    import jax.core as core
 
     def walk(jaxpr):
         n = 0
@@ -78,12 +69,7 @@ def count_pallas_calls(closed_jaxpr) -> int:
             if eqn.primitive.name == "pallas_call":
                 n += 1
             for val in eqn.params.values():
-                vals = val if isinstance(val, (list, tuple)) else [val]
-                for v in vals:
-                    if isinstance(v, core.ClosedJaxpr):
-                        n += walk(v.jaxpr)
-                    elif isinstance(v, core.Jaxpr):
-                        n += walk(v)
+                n += sum(walk(sub) for sub in sub_jaxprs(val))
         return n
 
     return walk(closed_jaxpr.jaxpr)
@@ -104,44 +90,21 @@ def smem_spec():
     This is the scalar-operand path for PRNG-bearing kernels — the
     seed rides as data (visible to jit, binding metadata and the
     contract checker), never as a Python-level constant baked into the
-    trace.  ``pltpu`` memory spaces are version-sensitive spelling, so
-    the helper lives here with :func:`vmem`.
+    trace.
     """
     from jax.experimental import pallas as pl
 
-    space = getattr(pltpu, "SMEM", None)
-    if space is None:  # pragma: no cover - future jax spelling
-        space = pltpu.TPUMemorySpace.SMEM
-    return pl.BlockSpec(memory_space=space)
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """``shard_map`` under either jax spelling.
-
-    jax ≥ 0.6 promotes it to ``jax.shard_map`` and renames
-    ``check_rep`` → ``check_vma``; jax 0.4.x has only
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
+    """``jax.shard_map``; ``check_rep`` is passed as ``check_vma``.
     Usable directly or as ``functools.partial(shard_map, mesh=...)``
-    decoration, exactly like the upstream function.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: PLC0415
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_rep)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
+    decoration, exactly like the upstream function."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
-@contextlib.contextmanager
 def set_mesh(mesh):
-    """Make ``mesh`` the ambient device mesh for the enclosed block."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield
-    else:
-        with mesh:
-            yield
+    """Context manager making ``mesh`` the ambient device mesh."""
+    return jax.set_mesh(mesh)

@@ -22,13 +22,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import autotune
-from .compat import tpu_compiler_params
 from .plan import BlockDef, KernelPlan, ScratchDef, launch_args
 
 
 # --------------------------------------------------------------------------
 # kernel bodies
 # --------------------------------------------------------------------------
+
+#: dot_general dimension numbers: x @ q, and xᵀ @ p without materializing
+#: the transpose (contract the leading, streamed dimension)
+NN = (((1,), (0,)), ((), ()))
+TN = (((0,), (0,)), ((), ()))
+
+
+def mxu_dot(x: jax.Array, y: jax.Array, dims) -> jax.Array:
+    """The MXU product of a kernel body, accumulated in f32.
+
+    Two f32 operands contract at full f32 precision (Mosaic's fp32
+    contract precision, several bf16 passes); the default would be one
+    bf16 pass.  Narrower operands take the default single pass: their
+    products are exact in f32, and Mosaic refuses fp32 precision on
+    them."""
+    f32 = jnp.float32
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == y.dtype == f32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(x, y, dims, precision=precision,
+                               preferred_element_type=f32)
 
 
 def _mm_nn_kernel(x_ref, q_ref, o_ref, acc_ref, *, n_k_steps: int):
@@ -39,12 +58,7 @@ def _mm_nn_kernel(x_ref, q_ref, o_ref, acc_ref, *, n_k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...],
-        q_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += mxu_dot(x_ref[...], q_ref[...], NN)
 
     @pl.when(k_step == n_k_steps - 1)
     def _flush():
@@ -59,12 +73,7 @@ def _mm_tn_kernel(x_ref, p_ref, o_ref, acc_ref, *, n_k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...],
-        p_ref[...],
-        (((0,), (0,)), ((), ())),  # xᵀ p without materializing the transpose
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += mxu_dot(x_ref[...], p_ref[...], TN)
 
     @pl.when(k_step == n_k_steps - 1)
     def _flush():
@@ -217,10 +226,7 @@ def pallas_matmul(
 
     out = pl.pallas_call(
         kernel,
-        **launch_args(plan),
+        **launch_args(plan, ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
     )(xp, yp)
     return out[:M, :N]

@@ -9,10 +9,24 @@ operand/output shapes, scratch allocations and dtypes.  Because the
 wrapper and the static checker (:mod:`repro.analysis.kernel_check`)
 consume the *same* plan object, the checker verifies exactly what runs
 — grid × block × index-map consistency, full output coverage, VMEM
-residency against the shared budget
-(:data:`repro.kernels.matmul.VMEM_BLOCK_ELEMS`) and the
-bf16-in/f32-accum dtype rules — with no device and no duplicated
-sizing logic that could drift.
+residency against the shared per-buffer budget
+(:data:`repro.kernels.matmul.VMEM_BLOCK_ELEMS`) and against the scoped
+VMEM limit the launch requests, and the bf16-in/f32-accum dtype rules —
+with no device and no duplicated sizing logic that could drift.
+
+VMEM accounting.  Mosaic pipelines every blocked operand and output
+through two buffers, keeps scratch resident, and keeps the kernel body's
+values (loaded blocks, the transposed operand of a TN contraction,
+products, running sums) on a VMEM stack beside them.  It refuses a
+kernel whose allocation exceeds the scoped VMEM limit (16 MiB unless the
+launch passes ``vmem_limit_bytes``).  :attr:`KernelPlan.vmem_bytes`
+counts the buffers, :attr:`KernelPlan.vmem_need_bytes` charges the body
+as much again plus :data:`VMEM_STACK_SLACK_BYTES`, and every launch
+requests :attr:`KernelPlan.vmem_limit_bytes`, that need rounded up and
+never below the default.  Under the default limit the TPU compiler
+refused the recompute powerpass at 512 × 2^19, k̃ = 970 (16 MiB of
+buffers) and the sharded sweep at 512 × 2^17, k̃ = 256 (16.5 MiB of
+buffers, 34.46 MiB in all).
 
 A ``plan_*`` function returns ``None`` when the shape is degenerate
 for its fused kernel (the documented unfused-fallback condition); the
@@ -26,6 +40,20 @@ import dataclasses
 from typing import Callable, Tuple
 
 IndexMap = Callable[..., Tuple[int, ...]]
+
+#: Mosaic's scoped-VMEM limit for a launch that passes no
+#: ``vmem_limit_bytes``.
+MOSAIC_DEFAULT_VMEM_LIMIT = 16 << 20
+
+#: Slack above twice a plan's buffers.  Compiled for a described TPU v5e,
+#: every kernel at five shapes (512- and 256-row chunks, d up to 2^18,
+#: k̃ from 32 to 2060) needed at most its buffers twice over plus
+#: 1.46 MiB (the powerpass sweep at 512 × 2^17, k̃ = 256).
+VMEM_STACK_SLACK_BYTES = 2 << 20
+
+#: The largest scoped limit a launch may request: half of the 128 MiB of
+#: VMEM on a TPU v5e core, leaving the rest to XLA's own fusions.
+VMEM_LIMIT_CAP = 64 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,16 +133,45 @@ class KernelPlan:
             n *= g
         return n
 
+    @property
+    def vmem_bytes(self) -> int:
+        """VMEM the launch allocates: every blocked operand and output
+        double-buffered by the pipeline, plus the resident scratch."""
+        import jax.numpy as jnp
 
-def launch_args(plan: KernelPlan) -> dict:
+        def nbytes(buf):
+            return buf.elems * jnp.dtype(buf.dtype).itemsize
+
+        blocks = sum(map(nbytes, self.in_specs + self.out_specs))
+        return 2 * blocks + sum(map(nbytes, self.scratch))
+
+    @property
+    def vmem_need_bytes(self) -> int:
+        """VMEM the launch is charged: the buffers, as much again for
+        the body's values, and the slack."""
+        return 2 * self.vmem_bytes + VMEM_STACK_SLACK_BYTES
+
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """The scoped-VMEM limit the launch requests: the need rounded
+        up to a MiB, never below the default."""
+        need = -(-self.vmem_need_bytes // (1 << 20)) << 20
+        return max(MOSAIC_DEFAULT_VMEM_LIMIT, need)
+
+
+def launch_args(plan: KernelPlan, semantics=None) -> dict:
     """``pl.pallas_call`` keyword arguments realized from a plan —
     the one bridge from the declarative contract to a live launch, so
-    a wrapper cannot diverge from what the checker verified."""
+    a wrapper cannot diverge from what the checker verified.
+
+    ``semantics`` is the grid's Mosaic ``dimension_semantics``; the
+    default marks every axis ``"arbitrary"`` (sequential), which the
+    accumulating kernels need."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from .compat import smem_spec, vmem
+    from .compat import smem_spec, tpu_compiler_params, vmem
 
     out_specs = [pl.BlockSpec(b.shape, b.index_map) for b in plan.out_specs]
     out_shape = [jax.ShapeDtypeStruct(b.padded, jnp.dtype(b.dtype))
@@ -129,4 +186,8 @@ def launch_args(plan: KernelPlan) -> dict:
         out_shape=out_shape[0] if single else out_shape,
         scratch_shapes=[vmem(s.shape, jnp.dtype(s.dtype))
                         for s in plan.scratch],
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=tuple(semantics or
+                                      ("arbitrary",) * len(plan.grid)),
+            vmem_limit_bytes=plan.vmem_limit_bytes),
     )

@@ -93,9 +93,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import autotune, rand
-from .compat import tpu_compiler_params
-from .matmul import (_pad2, _pick_block, _round_up, pallas_matmul,
-                     pick_schedule, vmem_row_cap)
+from .matmul import (NN, TN, _pad2, _pick_block, _round_up, mxu_dot,
+                     pallas_matmul, pick_schedule, vmem_row_cap)
 from .plan import BlockDef, KernelPlan, ScalarDef, ScratchDef, launch_args
 
 
@@ -112,17 +111,12 @@ def _powerpass_kernel(a_ref, b_ref, q_ref, y_ref, p_acc, *, n_k_steps: int):
     def _init_p():
         p_acc[...] = jnp.zeros_like(p_acc)
 
-    p_acc[...] += jax.lax.dot_general(
-        b_ref[...], q_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    p_acc[...] += mxu_dot(b_ref[...], q_ref[...], NN)
 
     @pl.when(k_step == n_k_steps - 1)
     def _accumulate():
-        y_ref[...] += jax.lax.dot_general(  # aᵀ p without materializing aᵀ
-            a_ref[...], p_acc[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(y_ref.dtype)
+        # aᵀ p without materializing aᵀ
+        y_ref[...] += mxu_dot(a_ref[...], p_acc[...], TN).astype(y_ref.dtype)
 
 
 def resolve_blocks(
@@ -251,9 +245,6 @@ def power_project_accumulate(
         functools.partial(_powerpass_kernel, n_k_steps=plan.grid[2]),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
     )(ap, bp, qp)
     return out[:da, :kt]
 
@@ -286,17 +277,11 @@ def _powerpass_seeded_kernel(seed_ref, a_ref, b_ref, y_ref, p_acc, *,
         (k_step * bdb).astype(rand.U32), rand.U32(0),
         (bdb, ktp), row_limit=db, col_limit=kt,
     ).astype(q_dtype)
-    p_acc[...] += jax.lax.dot_general(
-        b_ref[...], q_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    p_acc[...] += mxu_dot(b_ref[...], q_tile, NN)
 
     @pl.when(k_step == n_k_steps - 1)
     def _accumulate():
-        y_ref[...] += jax.lax.dot_general(
-            a_ref[...], p_acc[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(y_ref.dtype)
+        y_ref[...] += mxu_dot(a_ref[...], p_acc[...], TN).astype(y_ref.dtype)
 
 
 def plan_powerpass_seeded(n: int, da: int, db: int, kt: int, dtype, *,
@@ -391,9 +376,6 @@ def power_project_accumulate_seeded(
                           bdb=bdb, ktp=ktp, db=db, kt=kt, q_dtype=q_dtype),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
     )(jnp.asarray(seed, jnp.uint32), ap, bp)
     return out[:da, :kt]
 
@@ -414,10 +396,7 @@ def _proj_stage_kernel(x_ref, q_ref, p_ref):
     def _init():
         p_ref[...] = jnp.zeros_like(p_ref)
 
-    p_ref[...] += jax.lax.dot_general(
-        x_ref[...], q_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    p_ref[...] += mxu_dot(x_ref[...], q_ref[...], NN)
 
 
 def _proj_stage_seeded_kernel(seed_ref, x_ref, p_ref, *,
@@ -436,10 +415,7 @@ def _proj_stage_seeded_kernel(seed_ref, x_ref, p_ref, *,
         (k_step * bd).astype(rand.U32), rand.U32(0),
         (bd, ktp), row_limit=d, col_limit=kt,
     ).astype(q_dtype)
-    p_ref[...] += jax.lax.dot_general(
-        x_ref[...], q_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    p_ref[...] += mxu_dot(x_ref[...], q_tile, NN)
 
 
 def _powerpass_sweep_kernel(a_ref, p_ref, y_ref):
@@ -453,10 +429,8 @@ def _powerpass_sweep_kernel(a_ref, p_ref, y_ref):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    y_ref[...] += jax.lax.dot_general(  # aᵀ p without materializing aᵀ
-        a_ref[...], p_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    # aᵀ p without materializing aᵀ
+    y_ref[...] += mxu_dot(a_ref[...], p_ref[...], TN)
 
 
 def plan_proj_stage(n: int, d: int, kt: int, dtype, *,
@@ -629,17 +603,11 @@ def _staged_call(ap, bp, qp_or_seed, stage: KernelPlan, sweep: KernelPlan,
         body,
         **launch_args(stage),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(*operands)
     return pl.pallas_call(
         _powerpass_sweep_kernel,
         **launch_args(sweep),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(ap, p)
 
 
@@ -666,9 +634,6 @@ def proj_stage(x: jax.Array, q: jax.Array, *,
         _proj_stage_kernel,
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(xp, qp)
     return p[:n, :kt]
 
@@ -693,9 +658,6 @@ def proj_stage_seeded(x: jax.Array, seed: jax.Array, *, kt: int,
                           d=d, kt=kt, q_dtype=q_dtype),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(jnp.asarray(seed, jnp.uint32), xp)
     return p[:n, :kt]
 
@@ -720,8 +682,5 @@ def powerpass_sweep(a: jax.Array, p: jax.Array, *,
         _powerpass_sweep_kernel,
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(ap, pp)
     return out[:da, :kt]

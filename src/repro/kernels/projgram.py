@@ -63,9 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import autotune, rand
-from .compat import tpu_compiler_params
-from .matmul import (_pad2, _pick_block, _round_up, pallas_matmul,
-                     pick_schedule, vmem_row_cap)
+from .matmul import (NN, TN, _pad2, _pick_block, _round_up, mxu_dot,
+                     pallas_matmul, pick_schedule, vmem_row_cap)
 from .plan import BlockDef, KernelPlan, ScalarDef, ScratchDef, launch_args
 from .powerpass import (_proj_stage_kernel, _proj_stage_seeded_kernel,
                         plan_proj_stage, plan_proj_stage_seeded)
@@ -86,19 +85,15 @@ def _projgram_kernel(x_ref, q_ref, p_ref, c_ref, acc_ref,
     def _init_p():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], q_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += mxu_dot(x_ref[...], q_ref[...], NN)
 
     @pl.when(d_step == n_d_steps - 1)
     def _flush():
         p = acc_ref[...]
         p_ref[...] = p.astype(p_ref.dtype)
         pj = acc_ref[:, pl.ds(c_step * block_c, block_c)]
-        c_ref[...] += jax.lax.dot_general(  # Pᵀ P[:, bucket] on the MXU
-            p, pj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(c_ref.dtype)
+        # Pᵀ P[:, bucket] on the MXU
+        c_ref[...] += mxu_dot(p, pj, TN).astype(c_ref.dtype)
 
 
 def resolve_blocks(
@@ -217,9 +212,6 @@ def projgram(
                           block_c=plan.out_specs[1].shape[1]),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
     )(xp, qp)
     return p[:n, :kt], c[:kt, :kt]
 
@@ -248,19 +240,14 @@ def _projgram_seeded_kernel(seed_ref, x_ref, p_ref, c_ref, acc_ref, *,
         (d_step * bd).astype(rand.U32), rand.U32(0),
         (bd, ktp), row_limit=d, col_limit=kt,
     ).astype(q_dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], q_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += mxu_dot(x_ref[...], q_tile, NN)
 
     @pl.when(d_step == n_d_steps - 1)
     def _flush():
         p = acc_ref[...]
         p_ref[...] = p.astype(p_ref.dtype)
         pj = acc_ref[:, pl.ds(c_step * block_c, block_c)]
-        c_ref[...] += jax.lax.dot_general(
-            p, pj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(c_ref.dtype)
+        c_ref[...] += mxu_dot(p, pj, TN).astype(c_ref.dtype)
 
 
 def plan_projgram_seeded(n: int, d: int, kt: int, dtype, *,
@@ -347,9 +334,6 @@ def projgram_seeded(
                           bd=bd, ktp=ktp, d=d, kt=kt, q_dtype=q_dtype),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
     )(jnp.asarray(seed, jnp.uint32), xp)
     return p[:n, :kt], c[:kt, :kt]
 
@@ -372,9 +356,7 @@ def _gram_sweep_kernel(p_ref, c_ref, *, block_c: int):
 
     p = p_ref[...]
     pj = p_ref[:, pl.ds(c_step * block_c, block_c)]
-    c_ref[...] += jax.lax.dot_general(  # Pᵀ P[:, bucket] on the MXU
-        p, pj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    c_ref[...] += mxu_dot(p, pj, TN)  # Pᵀ P[:, bucket] on the MXU
 
 
 def plan_gram_sweep(n: int, kt: int, *,
@@ -489,18 +471,12 @@ def _staged_gram_call(xp, q_or_seed, stage: KernelPlan, gram: KernelPlan,
         body,
         **launch_args(stage),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(*operands)
     c = pl.pallas_call(
         functools.partial(_gram_sweep_kernel,
                           block_c=gram.out_specs[0].shape[1]),
         **launch_args(gram),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(p)
     return p, c
 
@@ -527,8 +503,5 @@ def gram_sweep(p: jax.Array, *, interpret: bool = False) -> jax.Array:
                           block_c=plan.out_specs[0].shape[1]),
         **launch_args(plan),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
     )(pp)
     return c[:kt, :kt]

@@ -54,10 +54,14 @@ from repro.core import exact_cca, feasibility_errors
 from repro.core.rcca import DEFAULT_ENGINE, randomized_cca_iterator
 from repro.core.rcca_dist import dist_randomized_cca
 from repro.data import PlantedCCAData
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Run the fit; return what it reported (``sum_rho``, and where the
+    corpus fits the evaluation budget ``feasibility`` and, at smoke
+    scale, ``oracle_gap``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--mode", default="dist", choices=["dist", "stream"])
@@ -117,7 +121,8 @@ def main(argv=None):
     ap.add_argument("--devices-per-worker", type=int, default=4,
                     help="local devices each hybrid worker folds merge "
                          "groups over (spawned with the forced-host-"
-                         "device XLA flag, so it works on CPU hosts)")
+                         "device XLA flag: a CPU rehearsal; cluster and "
+                         "hybrid refuse TPU hosts)")
     ap.add_argument("--trace", default=None, metavar="DIR", nargs="?",
                     const="1",
                     help="record a repro.obs trace of the fit (spans + "
@@ -128,6 +133,7 @@ def main(argv=None):
                          "(default rcca_trace/)")
     args = ap.parse_args(argv)
     args.prefetch = args.prefetch if args.prefetch == "auto" else int(args.prefetch)
+    use_compile_cache()
 
     if args.trace:
         import os
@@ -309,22 +315,26 @@ def main(argv=None):
         from repro.obs import report as obs_report
         print(obs_report.render(obs_report.analyze(obs.trace_dir())))
 
+    report = {"sum_rho": float(rho.sum())}
     if A is None:
         print("[cca] corpus larger than the eval budget — skipping "
               "materialized feasibility/oracle checks")
-        return
+        return report
 
     lam_a = float(res.diagnostics["lam_a"])
     lam_b = float(res.diagnostics["lam_b"])
     feas = feasibility_errors(jnp.asarray(A), jnp.asarray(B),
                               jnp.asarray(res.Xa), jnp.asarray(res.Xb), lam_a, lam_b)
-    print("[cca] feasibility:", {k: float(v) for k, v in feas.items()})
+    report["feasibility"] = {k: float(v) for k, v in feas.items()}
+    print("[cca] feasibility:", report["feasibility"])
 
     if args.smoke:
         ex = exact_cca(jnp.asarray(A), jnp.asarray(B), rcca.k, lam_a, lam_b)
         gap = float(np.sum(np.asarray(ex.rho)) - rho.sum())
+        report["oracle_gap"] = gap
         print(f"[cca] exact-oracle objective gap: {gap:.5f} "
               f"(exact {float(np.sum(np.asarray(ex.rho))):.4f})")
+    return report
 
 
 if __name__ == "__main__":

@@ -45,6 +45,7 @@ import numpy as np
 from repro.data import PlantedCCAData
 from repro.core.rcca import DEFAULT_ENGINE, RCCAConfig
 from repro.exec import FitState, Local, Sharded, delta_refit, fit_with_state
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import (BatchedProjector, CorpusIndex, DriftMonitor,
                          ModelRegistry)
 from repro.store import (ViewStoreReader, extend_chunks, ingest_chunks,
@@ -94,6 +95,7 @@ def main(argv=None) -> int:
                     help="record a repro.obs trace (spans for fit + "
                          "serve batches, drift/swap/occupancy counters)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.trace:
         from repro import obs

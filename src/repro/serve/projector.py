@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.linalg import full_f32
 
 from .registry import ServedModel
 
@@ -47,7 +48,7 @@ from .registry import ServedModel
 @functools.lru_cache(maxsize=64)
 def _project_jit(dim: int, k: int, bucket: int):
     """One compiled projection per (input dim, k, padded batch) shape."""
-    return jax.jit(lambda X, x: x @ X)
+    return jax.jit(full_f32(lambda X, x: x @ X))
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -250,6 +251,7 @@ class CorpusIndex:
                 f"embeddings must be (n, k={model.k}), got {self.emb.shape}")
 
     @classmethod
+    @full_f32
     def from_store(cls, model: ServedModel, store, view: str = "b",
                    *, max_rows: Optional[int] = None) -> "CorpusIndex":
         """Project one view of a store chunk-by-chunk into an index."""

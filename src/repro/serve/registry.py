@@ -41,6 +41,8 @@ import numpy as np
 
 from repro import obs
 from repro.ckpt import load_flat, load_metadata, save_pytree
+from repro.core.linalg import full_f32
+
 
 _LEAVES = ("Xa", "Xb", "rho", "Qa", "Qb")
 _VDIR_RE = re.compile(r"^v(\d{5})$")
@@ -74,10 +76,12 @@ class ServedModel:
     def k(self) -> int:
         return int(self.Xa.shape[1])
 
+    @full_f32
     def project_a(self, x) -> jnp.ndarray:
         """x ↦ Φᵃx: rows of view A into the canonical space."""
         return jnp.asarray(x) @ self.Xa
 
+    @full_f32
     def project_b(self, x) -> jnp.ndarray:
         return jnp.asarray(x) @ self.Xb
 

@@ -252,7 +252,33 @@ def test_rcca104_vmem_budget():
     assert codes(vs) == ["RCCA104", "RCCA104"]  # the in block and out block
     vs = kernel_check.check_plan(
         _plan_2x2(scratch=(ScratchDef((4096, 4096), "float32"),)))
+    # the scratch buffer, and the 64 MiB footprint over the limit cap
+    assert codes(vs) == ["RCCA104", "RCCA104"]
+
+
+def test_rcca104_refuses_the_plan_mosaic_refused(monkeypatch):
+    """The recompute powerpass of a paper-width chunk (512 rows,
+    d = 2^19, k̃ = 970) holds exactly 16 MiB of double-buffered blocks
+    and scratch, and its body's values need more beside them.  Mosaic
+    refused it when its launch requested no scoped limit (16 MiB by
+    default); the checker refuses that launch too, and passes the plan
+    at the limit the launch now requests."""
+    from repro.kernels import compat
+    from repro.kernels.plan import MOSAIC_DEFAULT_VMEM_LIMIT
+    from repro.kernels.powerpass import plan_powerpass
+
+    plan = plan_powerpass(512, 1 << 19, 1 << 19, 970, "float32")
+    assert plan.vmem_bytes == 16 << 20
+    assert plan.vmem_need_bytes > MOSAIC_DEFAULT_VMEM_LIMIT
+    assert plan.vmem_limit_bytes == 34 << 20
+    assert kernel_check.check_plan(plan) == []
+
+    real = compat.tpu_compiler_params
+    monkeypatch.setattr(compat, "tpu_compiler_params",
+                        lambda *, vmem_limit_bytes=None, **kw: real(**kw))
+    vs = kernel_check.check_plan(plan)
     assert codes(vs) == ["RCCA104"]
+    assert "scoped VMEM limit 16777216 its launch requests" in vs[0].message
 
 
 def test_rcca105_dtype_rules():

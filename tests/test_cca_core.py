@@ -15,6 +15,7 @@ from repro.core import (
     randomized_cca,
     randomized_cca_iterator,
     randomized_cca_streaming,
+    streamed_feasibility_errors,
 )
 from repro.core.rcca import RCCAConfig
 from repro.data import planted_views
@@ -53,6 +54,18 @@ def test_rcca_matches_exact(views):
     errs = feasibility_errors(A, B, r.Xa, r.Xb, LAM, LAM)
     for name, v in errs.items():
         assert float(v) < 1e-4, (name, float(v))
+
+
+def test_streamed_feasibility_matches_materialized(views):
+    """The chunked residuals (k × k Grams folded over row chunks) agree
+    with the one-shot form: the same sums in another order."""
+    A, B = views
+    sol = exact_cca(A, B, K, LAM, LAM)
+    chunks = [(A[i:i + 700], B[i:i + 700]) for i in range(0, A.shape[0], 700)]
+    got = streamed_feasibility_errors(chunks, sol.Xa, sol.Xb, LAM, LAM)
+    want = feasibility_errors(A, B, sol.Xa, sol.Xb, LAM, LAM)
+    for name in want:
+        assert abs(float(got[name]) - float(want[name])) < 1e-5, name
 
 
 def test_rcca_objective_matches_rho(views):

@@ -178,6 +178,16 @@ def _publish_round(store, cluster_dir, pass_idx=0, kind="power",
     return expect
 
 
+def test_coordinator_refuses_tpu_host(store, tmp_path, monkeypatch):
+    """One process per chip: a coordinator on a TPU host would hold the
+    chip its workers need, so it refuses at once instead of hanging at
+    the barrier."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        ClusterCoordinator(store, CFG, str(tmp_path / "c"), n_workers=2)
+    assert not (tmp_path / "c").exists()
+
+
 def test_worker_killed_mid_shard_resumes_from_cursor(store, tmp_path):
     """A killed worker re-run with the same shard id picks up mid-shard:
     published groups are skipped, the in-flight group resumes from the

@@ -29,8 +29,9 @@ def test_dist_rcca_matches_reference():
         from repro.core.rcca import RCCAConfig, randomized_cca
         from repro.core.rcca_dist import dist_randomized_cca
         from repro.core import feasibility_errors
+        from repro.launch.mesh import make_host_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
         key = jax.random.PRNGKey(0)
         n, da, db, k = 2048, 64, 32, 5
         kz, ka, kb, kn = jax.random.split(key, 4)
@@ -50,6 +51,45 @@ def test_dist_rcca_matches_reference():
         np.testing.assert_allclose(np.asarray(rd.rho), np.asarray(rr.rho), atol=2e-4)
         print("OK")
     """)
+
+
+def test_dist_rcca_nu_on_sharded_features():
+    """ν-regularized fit with the features sharded: λ = ν‖A‖²_F / d sums
+    the trace over every feature shard, so each shard of X is whitened
+    with the same λ and the fit is feasible — for every engine and
+    collective."""
+    run_with_devices("""
+        import jax, numpy as np
+        from repro.core.rcca import RCCAConfig, randomized_cca
+        from repro.core.rcca_dist import dist_randomized_cca
+        from repro.core import feasibility_errors
+        from repro.launch.mesh import make_host_mesh
+
+        rng = np.random.default_rng(3)
+        n, da, db, k = 256, 64, 48, 4
+        Z = rng.standard_normal((n, k))
+        A = (Z @ rng.standard_normal((k, da))
+             + rng.standard_normal((n, da))).astype(np.float32)
+        B = (Z @ rng.standard_normal((k, db))
+             + rng.standard_normal((n, db))).astype(np.float32)
+        cfg = RCCAConfig(k=k, p=12, q=1, nu=0.01)
+        key = jax.random.PRNGKey(1)
+        ref = randomized_cca(A, B, cfg, key)
+        lam = [float(ref.diagnostics[f"lam_{v}"]) for v in "ab"]
+        mesh = make_host_mesh((2, 2), ("data", "model"))
+        for engine, collective in (("jnp", "fused"), ("kernels", "fused"),
+                                   ("kernels", "unfused")):
+            r = dist_randomized_cca(A, B, cfg, key, mesh, row_axes=("data",),
+                                    col_axis="model", microbatch=32,
+                                    engine=engine, collective=collective)
+            got = [float(r.diagnostics[f"lam_{v}"]) for v in "ab"]
+            np.testing.assert_allclose(got, lam, rtol=1e-5)
+            errs = feasibility_errors(A, B, r.Xa, r.Xb, *lam)
+            assert all(float(v) < 1e-4 for v in errs.values()), (engine, errs)
+            np.testing.assert_allclose(np.asarray(r.rho),
+                                       np.asarray(ref.rho), atol=2e-4)
+        print("OK")
+    """, n=4)
 
 
 def test_dist_rcca_mesh_shapes_agree():
@@ -81,11 +121,12 @@ def test_compressed_psum_error_feedback():
     run_with_devices("""
         import functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed import psum_int8_ef
+        from repro.kernels.compat import shard_map
+        from repro.launch.mesh import make_host_mesh
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_host_mesh((4,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 64, 256))
 
         @functools.partial(shard_map, mesh=mesh, in_specs=P("data"), out_specs=(P("data"), P("data")), check_rep=False)

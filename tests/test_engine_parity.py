@@ -1,4 +1,4 @@
-"""Compat-shim behaviour (both jax API spellings), kernel-vs-jnp engine
+"""Compat-shim behaviour, kernel-vs-jnp engine
 parity for the data-pass drivers, the fused power-pass acceptance
 criteria, and the block-size autotuner."""
 
@@ -29,13 +29,11 @@ from repro.data import planted_views
 
 
 def test_compiler_params_old_spelling():
-    """On jax 0.4.x (no pltpu.CompilerParams) the shim must build a
-    TPUCompilerParams; on newer jax, whichever class pallas accepts."""
+    """The shim builds the ``pltpu.CompilerParams`` pallas accepts."""
     params = compat.tpu_compiler_params(
         dimension_semantics=("parallel", "arbitrary")
     )
-    expected = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    assert isinstance(params, expected)
+    assert isinstance(params, pltpu.CompilerParams)
     assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
 
 
@@ -50,18 +48,6 @@ def test_compiler_params_new_spelling(monkeypatch):
                         raising=False)
     params = compat.tpu_compiler_params(dimension_semantics=("arbitrary",))
     assert isinstance(params, FakeCompilerParams)
-
-
-def test_set_mesh_old_spelling():
-    """Without jax.set_mesh the shim enters the mesh's own context."""
-    if hasattr(jax, "set_mesh"):
-        pytest.skip("this jax has jax.set_mesh; old spelling unreachable")
-    mesh = jax.make_mesh((1,), ("data",))
-    from jax._src import mesh as mesh_lib
-
-    with compat.set_mesh(mesh):
-        assert mesh_lib.thread_resources.env.physical_mesh == mesh
-    assert mesh_lib.thread_resources.env.physical_mesh.empty
 
 
 def test_set_mesh_new_spelling(monkeypatch):
@@ -81,16 +67,16 @@ def test_set_mesh_new_spelling(monkeypatch):
 
 
 def test_cost_analysis_normalized():
-    class FakeCompiledList:
-        def cost_analysis(self):
-            return [{"flops": 7.0}]
-
     class FakeCompiledDict:
         def cost_analysis(self):
             return {"flops": 7.0}
 
-    assert compat.cost_analysis(FakeCompiledList())["flops"] == 7.0
+    class FakeCompiledEmpty:
+        def cost_analysis(self):
+            return None
+
     assert compat.cost_analysis(FakeCompiledDict())["flops"] == 7.0
+    assert compat.cost_analysis(FakeCompiledEmpty()) == {}
 
 
 def test_resolve_engine():

@@ -41,7 +41,7 @@ from repro.core.rcca import (
 )
 from repro.cluster import partials as pt
 from repro.data import PlantedCCAData
-from repro.kernels import ops, rand
+from repro.kernels import compat, ops, rand
 from repro.kernels.plan import BlockDef, KernelPlan, ScalarDef
 from repro.store import PassRunner, ingest_planted
 from repro.store.prefetch import ChunkPrefetcher
@@ -232,16 +232,6 @@ def test_fit_seeded_matches_oracle_bitwise(engine, cfg):
 # --------------------------------------------------------------------------
 
 
-def _sub_jaxprs(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
-        yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
-        yield p
-    elif isinstance(p, (tuple, list)):
-        for q in p:
-            yield from _sub_jaxprs(q)
-
-
 def _shapes(jaxpr, out):
     """All aval shapes in a jaxpr, recursing through sub-jaxprs but NOT
     into pallas kernels — in-VMEM tiles are the point of the design;
@@ -258,7 +248,7 @@ def _shapes(jaxpr, out):
         if "pallas" in eqn.primitive.name:
             continue
         for p in eqn.params.values():
-            for sub in _sub_jaxprs(p):
+            for sub in compat.sub_jaxprs(p):
                 _shapes(sub, out)
     return out
 

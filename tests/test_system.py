@@ -90,7 +90,47 @@ def test_train_driver_integration(tmp_path):
 def test_cca_driver_integration():
     from repro.launch.cca_fit import main as cca_main
 
-    cca_main(["--smoke", "--mode", "dist"])
+    report = cca_main(["--smoke", "--mode", "dist"])
+    assert report["oracle_gap"] < 0.01, report
+    assert all(v < 1e-4 for v in report["feasibility"].values()), report
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when
+    set (jax reads it; nothing else is configured), otherwise the fixed
+    .jax_cache/ directory of the checkout."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/srv/jax-cache")
+    assert compile_cache.use_compile_cache() == "/srv/jax-cache"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.use_compile_cache() == str(checkout / ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir",
+                        str(checkout / ".jax_cache"))]
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py proves the chip path: on a CPU it exits non-zero
+    before any phase and prints no result line."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=checkout,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs a TPU" in proc.stderr
 
 
 def test_serve_driver_integration():
