@@ -147,9 +147,9 @@ def run_fold(indexed_chunks, update_fn, acc: SegmentedAccumulator, Qa, Qb, *,
     Under ``RCCA_TRACE`` the loop records an ``io_wait`` span around
     each source pull and a ``chunk`` span around each fold (the
     ``on_chunk`` callback rides inside it — in-flight bounding IS the
-    device-compute wait), stamped with ``span_attrs`` and, when
-    ``cost_fn(a, b)`` is given, the cost-model flops/bytes; per-kernel
-    totals are emitted as one ``kernel_cost`` counter at loop end.
+    device-compute wait), stamped with ``span_attrs``; when
+    ``cost_fn(a, b)`` is given, its per-kernel cost-model totals are
+    emitted as one ``kernel_cost`` counter at loop end.
     With tracing off the loop below runs byte-for-byte unchanged.
     """
     if not obs.enabled():
@@ -176,8 +176,6 @@ def run_fold(indexed_chunks, update_fn, acc: SegmentedAccumulator, Qa, Qb, *,
         attrs = dict(base, chunk=chunk_idx)
         if cost_fn is not None:
             cost = cost_fn(a, b)
-            attrs["flops"] = cost["flops"]
-            attrs["bytes"] = cost["bytes"]
             if cost.get("schedule") is not None:
                 attrs["schedule"] = cost["schedule"]
             kernel_parts.extend(cost["kernels"])
@@ -254,9 +252,9 @@ def fold_groups_on_mesh(get_chunk, groups: Sequence[int], update_fn,
     loop below pops (padding only replicates an id already fetched), so
     the reads — and therefore the folded values — are bitwise unchanged
     from the old synchronous gather.  Under ``RCCA_TRACE`` each batch
-    records ``gather`` and ``mesh_fold`` spans (the latter stamped with
-    cost-model flops/bytes) plus one ``io`` counter from the prefetcher
-    and a ``kernel_cost`` counter for the folded chunks.
+    records ``gather`` and ``mesh_fold`` spans, plus one ``io`` counter
+    from the prefetcher and a ``kernel_cost`` counter for the folded
+    chunks.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -305,11 +303,9 @@ def fold_groups_on_mesh(get_chunk, groups: Sequence[int], update_fn,
                     b_blk = jax.device_put(
                         np.stack([blocks[g][1] for g in padded]), shard)
                 fattrs = dict(base, groups=len(ids))
-                if chunk_cost is not None:
-                    fattrs["flops"] = chunk_cost["flops"] * len(ids) * G
-                    fattrs["bytes"] = chunk_cost["bytes"] * len(ids) * G
-                    if chunk_cost.get("schedule") is not None:
-                        fattrs["schedule"] = chunk_cost["schedule"]
+                if (chunk_cost is not None
+                        and chunk_cost.get("schedule") is not None):
+                    fattrs["schedule"] = chunk_cost["schedule"]
                 with obs.span("mesh_fold", **fattrs):
                     out = fold_batch(a_blk, b_blk, Qa, Qb)
                     for i, g in enumerate(ids):
@@ -435,6 +431,31 @@ class PassEngine:
 
         return finalize_result(fstats, Qa, Qb, self.cfg, da, db)
 
+    # The pass boundaries.  Under RCCA_TRACE each is a ``q_update`` or
+    # ``finish`` span, with the pass's ``merge`` (the tree's result)
+    # nested where the boundary first needs the stats.
+
+    def _q_update(self, acc, Qa, Qb, pass_idx: int, da: int, db: int, *,
+                  site: str):
+        """Qa, Qb for the pass after power pass ``pass_idx``."""
+        from repro.core.rcca import power_update_Q
+
+        with obs.span("q_update", pass_idx=pass_idx, site=site):
+            if self.cfg.center:  # μ corrections need the actual Ω
+                Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)
+            with obs.span("merge", pass_idx=pass_idx, site=site):
+                stats = acc.result()
+            return power_update_Q(stats, Qa, Qb, self.cfg)
+
+    def _finalize(self, acc, Qa, Qb, pass_idx: int, da: int, db: int, *,
+                  site: str):
+        """The fit's result after the final pass ``pass_idx``."""
+        with obs.span("finish", site=site):
+            Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)  # q = 0
+            with obs.span("merge", pass_idx=pass_idx, site=site):
+                stats = acc.result()
+            return self._finish(stats, Qa, Qb, da, db)
+
     def cost_fn(self, kind: str, seeded: bool):
         """Cost-model ``(a, b) -> flops/bytes`` closure for one pass's
         chunk updates, or ``None`` when tracing is off."""
@@ -470,8 +491,6 @@ class PassEngine:
     def _run_stream(self, source_factory, da, db, key, *,
                     n_chunks=None, resume_state=None, on_pass_end=None,
                     on_pass_complete=None):
-        from repro.core.rcca import power_update_Q
-
         cfg = self.cfg
         sanitize.reset()
         Qa, Qb = self._init_payload(key, da, db)
@@ -515,12 +534,10 @@ class PassEngine:
                 if on_pass_complete is not None:
                     on_pass_complete(pass_idx, kind, acc, Qa, Qb)
                 if kind == "power":
-                    if cfg.center:  # μ corrections need the actual Ω
-                        Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)
-                    Qa, Qb = power_update_Q(acc.result(), Qa, Qb, cfg)
+                    Qa, Qb = self._q_update(acc, Qa, Qb, pass_idx, da, db,
+                                            site="stream")
 
-        Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)  # q = 0 finalize
-        res = self._finish(acc.result(), Qa, Qb, da, db)
+        res = self._finalize(acc, Qa, Qb, pass_idx, da, db, site="stream")
         if sanitize.enabled():
             res.diagnostics["sanitize"] = sanitize.snapshot()
             sanitize.dump()
@@ -549,8 +566,7 @@ class PassEngine:
 
     def _run_mesh(self, access, key, *, mesh=None, prefetch: int = 2,
                   on_pass_complete=None):
-        from repro.core.rcca import (power_update_Q, seeded_update_fn,
-                                     update_fn)
+        from repro.core.rcca import seeded_update_fn, update_fn
 
         topo = self.topology if isinstance(self.topology, Sharded) else Sharded()
         if topo.col_axis is not None:
@@ -600,12 +616,10 @@ class PassEngine:
                 if on_pass_complete is not None:
                     on_pass_complete(pass_idx, kind, acc, Qa, Qb)
                 if kind == "power":
-                    if cfg.center:  # μ corrections need the actual Ω
-                        Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)
-                    Qa, Qb = power_update_Q(acc.result(), Qa, Qb, cfg)
+                    Qa, Qb = self._q_update(acc, Qa, Qb, pass_idx, da, db,
+                                            site="mesh")
 
-        Qa, Qb = self._boundary_Q(Qa, Qb, pass_idx, da, db)  # q = 0 finalize
-        res = self._finish(acc.result(), Qa, Qb, da, db)
+        res = self._finalize(acc, Qa, Qb, pass_idx, da, db, site="mesh")
         if sanitize.enabled():
             res.diagnostics["sanitize"] = sanitize.snapshot()
             sanitize.dump()

@@ -15,10 +15,11 @@ Reads every per-process ``trace-*.jsonl`` file and reconstructs:
   outside any span and the profile is lying by omission.
 * **roofline** — per-kernel cost-model totals (flops / bytes / calls,
   from the same :class:`~repro.kernels.plan.KernelPlan` geometry the
-  launches use, via the ``kernel_cost`` counters) joined with the
-  measured fold time (``chunk`` + ``mesh_fold`` spans carrying
-  cost-model attrs), giving achieved model-flops/s and arithmetic
-  intensity per pass kind and engine.
+  launches use, via the ``kernel_cost`` counters) and their arithmetic
+  intensity.  No rate: on an asynchronous device a span's host time is
+  dispatch time, not kernel time; device time comes from a
+  ``jax.profiler`` trace, where each span is an ``rcca.<name>``
+  annotation.
 * **io overlap** — per prefetch site, the fraction of read time hidden
   behind compute: ``(read_s - io_stall_s) / read_s`` from the ``io``
   counters the prefetcher emits on close.
@@ -35,9 +36,6 @@ import json
 from typing import Any, Dict, List, Optional
 
 from repro.obs.trace import load_events
-
-#: span names whose time is a leaf phase (no further decomposition)
-_FOLD_SPANS = ("chunk", "mesh_fold")
 
 
 def _spans_by_pid(events: List[dict]) -> Dict[int, List[dict]]:
@@ -130,35 +128,11 @@ def analyze(path: str) -> Dict[str, Any]:
             k["calls"] += int(f.get("calls", 0))
             k["flops"] += int(f.get("flops", 0))
             k["bytes"] += int(f.get("bytes", 0))
-    folds: Dict[Any, Dict[str, float]] = {}
-    for spans in by_pid.values():
-        for sp in spans:
-            if sp["name"] not in _FOLD_SPANS:
-                continue
-            a = sp.get("attrs", {})
-            if "flops" not in a:
-                continue
-            key = (str(a.get("kind", "?")), str(a.get("engine", "?")))
-            fd = folds.setdefault(key, {"s": 0.0, "flops": 0, "bytes": 0,
-                                        "n": 0})
-            fd["s"] += float(sp.get("dur", 0.0))
-            fd["flops"] += int(a["flops"])
-            fd["bytes"] += int(a.get("bytes", 0))
-            fd["n"] += 1
     report["roofline"] = {
         "kernels": {
             k: dict(v, intensity=round(v["flops"] / v["bytes"], 3)
                     if v["bytes"] else None)
             for k, v in sorted(kernels.items())
-        },
-        "folds": {
-            f"{kind}/{engine}": {
-                "n": fd["n"], "s": round(fd["s"], 4),
-                "flops": fd["flops"], "bytes": fd["bytes"],
-                "model_gflops_per_s": round(fd["flops"] / fd["s"] / 1e9, 3)
-                if fd["s"] else None,
-            }
-            for (kind, engine), fd in sorted(folds.items())
         },
     }
 
@@ -186,7 +160,10 @@ def analyze(path: str) -> Dict[str, Any]:
     merge_s = fit_s = 0.0
     for spans in by_pid.values():
         for sp in spans:
-            if sp["name"] == "merge":
+            # a single-process fit's own merges (site stream / mesh) are
+            # not the coordinator's tree
+            if sp["name"] == "merge" and (sp.get("attrs", {}).get("site")
+                                          not in ("stream", "mesh")):
                 merge_s += float(sp.get("dur", 0.0))
             elif sp["name"] == "fit" and (sp.get("attrs", {}).get("site")
                                           == "coordinator"):
@@ -270,11 +247,6 @@ def render(report: Dict[str, Any]) -> str:
         inten = f"{v['intensity']:.2f}" if v["intensity"] else "-"
         out.append(f"  {k:<20} {v['calls']:>7d} {v['flops']:>14d} "
                    f"{v['bytes']:>14d} {inten:>10}")
-    out.append("  fold spans (measured wall over cost-model work):")
-    for key, fd in report["roofline"]["folds"].items():
-        gf = (f"{fd['model_gflops_per_s']:.3f} model-GFLOP/s"
-              if fd["model_gflops_per_s"] is not None else "-")
-        out.append(f"    {key:<16} n={fd['n']:<5d} {fd['s']:8.3f}s  {gf}")
     out.append("")
     out.append("io overlap")
     for site, v in report["io"].items():

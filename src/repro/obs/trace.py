@@ -25,6 +25,12 @@ its own track):
   top-level ``op``/``path``/``meta`` keys keep ``check_trace`` working
   directly on an obs trace file.
 
+When ``RCCA_TRACE`` is set every span is also a
+``jax.profiler.TraceAnnotation`` named ``rcca.<name>`` (attributes stay
+in the JSONL record), entered and exited on the same thread, so a
+``jax.profiler`` trace taken meanwhile shows the fit's phases on the
+profiler's own clock, beside the device programs they launch.
+
 When ``RCCA_TRACE`` is unset every entry point is a no-op: ``span``
 returns a shared null context manager and ``counter`` returns before
 building the record, so the traced code path costs one environment
@@ -111,7 +117,7 @@ def _stack() -> List[int]:
 class _Span:
     """Context manager recording one span on exit (even when unwinding)."""
 
-    __slots__ = ("name", "attrs", "sid", "parent", "_t0", "_w0")
+    __slots__ = ("name", "attrs", "sid", "parent", "_t0", "_w0", "_note")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -122,12 +128,19 @@ class _Span:
         self.parent = st[-1] if st else None
         self.sid = next(_SIDS)
         st.append(self.sid)
+        # jax is imported here, not at module load: the report CLI and
+        # processes that never trace the device import this module too
+        import jax.profiler
+
+        self._note = jax.profiler.TraceAnnotation(f"rcca.{self.name}")
+        self._note.__enter__()
         self._w0 = wall()
         self._t0 = monotonic()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         dur = monotonic() - self._t0
+        self._note.__exit__(*exc)
         st = _stack()
         if st and st[-1] == self.sid:
             st.pop()

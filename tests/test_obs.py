@@ -24,6 +24,8 @@ The contract under test, in order of importance:
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -89,6 +91,77 @@ def test_trace_off_is_bitwise_invisible(store, tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 # 2. hybrid fit: spans nest, parents resolve, coverage >= 95%
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("topology", [Local(), Sharded()],
+                         ids=["stream", "mesh"])
+def test_pass_boundary_spans(store, tmp_path, monkeypatch, topology):
+    """Each fit has q ``q_update`` spans under their power pass, one
+    ``finish`` under the fit, and q + 1 ``merge`` spans, each under the
+    boundary that consumes the pass's stats."""
+    trace_dir = str(tmp_path / "trace")
+    monkeypatch.setenv("RCCA_TRACE", trace_dir)
+    _fit(store, tmp_path, topology=topology)
+    monkeypatch.delenv("RCCA_TRACE")
+    spans = [ev for ev in load_events(trace_dir) if ev.get("ev") == "span"]
+    by_sid = {sp["sid"]: sp for sp in spans}
+    named = lambda name: [sp for sp in spans if sp["name"] == name]
+    parent = lambda sp: by_sid[sp["parent"]]
+    site = {"Local": "stream", "Sharded": "mesh"}[type(topology).__name__]
+
+    q_updates, finishes, merges = (named(n) for n in ("q_update", "finish", "merge"))
+    assert len(q_updates) == CFG.q and len(finishes) == 1
+    assert len(merges) == CFG.q + 1
+    for sp in q_updates:
+        p = parent(sp)
+        assert p["name"] == "pass" and p["attrs"]["kind"] == "power"
+        assert sp["attrs"]["pass_idx"] == p["attrs"]["pass_idx"]
+    (fin,) = finishes
+    assert parent(fin)["name"] == "fit" and parent(fin)["attrs"]["site"] == site
+    assert [parent(m)["name"] for m in merges] == ["q_update"] * CFG.q + ["finish"]
+    assert [m["attrs"]["pass_idx"] for m in merges] == list(range(CFG.q + 1))
+    assert {sp["attrs"]["site"] for sp in q_updates + finishes + merges} == {site}
+
+
+def test_spans_are_profiler_annotations_only_when_tracing(tmp_path, monkeypatch):
+    """Under RCCA_TRACE a span is also a ``rcca.<name>`` profiler
+    annotation, entered and exited around the span; unset, none."""
+    from repro import obs
+
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    monkeypatch.delenv("RCCA_TRACE", raising=False)
+    with obs.span("q_update", pass_idx=0):
+        pass
+    assert log == []
+
+    monkeypatch.setenv("RCCA_TRACE", str(tmp_path / "trace"))
+    with obs.span("q_update", pass_idx=0):
+        with obs.span("merge"):
+            pass
+    assert log == [("enter", "rcca.q_update"), ("enter", "rcca.merge"),
+                   ("exit", "rcca.merge"), ("exit", "rcca.q_update")]
+    (rec,) = [ev for ev in load_events(str(tmp_path / "trace"))
+              if ev["name"] == "q_update"]
+    assert rec["attrs"] == {"pass_idx": 0}
+
+    # the report CLI and untraced processes load the module without jax
+    src = os.path.dirname(os.path.dirname(os.path.dirname(obs.__file__)))
+    code = ("import sys, repro.obs.trace, repro.obs.report; "
+            "sys.exit('jax' in sys.modules)")
+    assert subprocess.run([sys.executable, "-c", code],
+                          env=dict(os.environ, PYTHONPATH=src)).returncode == 0
 
 
 @pytest.fixture(scope="module")
