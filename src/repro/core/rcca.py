@@ -30,6 +30,7 @@ and products are corrected as  Āᵀ B̄ = AᵀB − n μa μbᵀ.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -379,17 +380,22 @@ def init_Q(key: jax.Array, da: int, db: int, cfg: RCCAConfig,
             krand.dense_omega(seed_b, db, cfg.sketch, cfg.dtype))
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
 @full_f32
 def power_update_Q(stats: PowerStats, Qa, Qb, cfg: RCCAConfig):
-    """Lines 10-11: close one range-finder pass (center + orth)."""
+    """Lines 10-11: close one range-finder pass (center + orth), as one
+    compiled program per (cfg, shapes).  ``full_f32`` sits inside the
+    jit, so every dot of the traced body carries HIGHEST precision."""
     Ya, Yb = centered_Y(stats, Qa, Qb, cfg.center)
     return orth(Ya.astype(cfg.dtype)), orth(Yb.astype(cfg.dtype))
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "da", "db"))
 @full_f32
 def finalize_result(fstats: FinalStats, Qa, Qb, cfg: RCCAConfig,
                     da: int, db: int) -> RCCAResult:
-    """Lines 19-25 from merged final-pass statistics."""
+    """Lines 19-25 from merged final-pass statistics, as one compiled
+    program per (cfg, da, db, shapes)."""
     Ca, Cb, F = centered_CF(fstats, Qa, Qb, cfg.center)
     lam_a, lam_b = resolve_lambdas(cfg, fstats.tr_a, fstats.tr_b, da, db)
     QtQa = sym((Qa.T @ Qa).astype(jnp.float32))
@@ -527,10 +533,15 @@ def randomized_cca_streaming(
     return eng.run(StackedChunks(A_chunks, B_chunks), key)
 
 
+_jit_cached = functools.lru_cache(maxsize=None)(jax.jit)
+
+
 def jit_update_fn(kind: str, engine: str):
     """The jitted per-chunk update for one pass flavor — the exact
-    function cluster workers and the iterator driver share."""
-    return jax.jit(update_fn(kind, engine))
+    function cluster workers and the streaming entries share.  One jit
+    wrapper per update function, so every fit reuses its dispatch
+    cache."""
+    return _jit_cached(update_fn(kind, engine))
 
 
 def update_fn(kind: str, engine: str):
@@ -590,19 +601,26 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
     raise ValueError(f"unknown pass kind {kind!r}")
 
 
+@functools.lru_cache(maxsize=None)
 def jit_seeded_update_fn(kind: str, kt: int, q_dtype):
     """Jitted :func:`seeded_update_fn` — what streaming drivers and
-    cluster workers run for a seeded pass."""
+    cluster workers run for a seeded pass (one wrapper per flavor)."""
     return jax.jit(seeded_update_fn(kind, kt, q_dtype))
 
 
+@functools.lru_cache(maxsize=None)
 def stats_init_fn(kind: str, da: int, db: int, sketch: int):
     """Zero accumulators for one pass flavor (f32 — the accumulator
-    precision every execution mode shares)."""
+    precision every execution mode shares): one compiled program per
+    (kind, da, db, k̃) that returns the whole stats pytree."""
     if kind == "power":
-        return lambda: init_power_stats(da, db, sketch, jnp.float32)
+        def zero_power_stats():
+            return init_power_stats(da, db, sketch, jnp.float32)
+        return jax.jit(zero_power_stats)
     if kind == "final":
-        return lambda: init_final_stats(sketch, da, db, jnp.float32)
+        def zero_final_stats():
+            return init_final_stats(sketch, da, db, jnp.float32)
+        return jax.jit(zero_final_stats)
     raise ValueError(f"unknown pass kind {kind!r}")
 
 

@@ -251,7 +251,7 @@ class SegmentedAccumulator:
         acc._in_group = max(0, next_chunk - acc.groups_done * group_chunks)
         acc._last_chunk = next_chunk - 1
         depth = PairwiseStack.depth_after(acc.groups_done)
-        acc.load_state({"current": init_fn(),
+        acc.load_state({"current": acc.current,
                         "stack": tuple(init_fn() for _ in range(depth))})
         return acc
 

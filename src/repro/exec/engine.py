@@ -511,12 +511,13 @@ class PassEngine:
             seeded = upd_seeded is not None and pass_idx == 0
             with obs.span("pass", pass_idx=pass_idx, kind=kind,
                           site="stream"):
-                acc = SegmentedAccumulator.structure(
-                    self._init_fn(kind, da, db), n_chunks, self.merge_group,
-                    start_chunk)
-                if acc_state is not None:
-                    acc.load_state(acc_state)
-                    acc_state = None
+                with obs.span("acc_init", pass_idx=pass_idx, site="stream"):
+                    acc = SegmentedAccumulator.structure(
+                        self._init_fn(kind, da, db), n_chunks,
+                        self.merge_group, start_chunk)
+                    if acc_state is not None:
+                        acc.load_state(acc_state)
+                        acc_state = None
                 source, offset = open_source(source_factory, start_chunk)
                 cb = None
                 if on_pass_end is not None:
@@ -600,8 +601,10 @@ class PassEngine:
             seeded = sd_raw is not None and pass_idx == 0
             raw = sd_raw[kind] if seeded else upd_raw[kind]
             jit = sd_jit[kind] if seeded else upd_jit[kind]
-            acc = SegmentedAccumulator(init_fns[kind], nc, self.merge_group)
             with obs.span("pass", pass_idx=pass_idx, kind=kind, site="mesh"):
+                with obs.span("acc_init", pass_idx=pass_idx, site="mesh"):
+                    acc = SegmentedAccumulator(init_fns[kind], nc,
+                                               self.merge_group)
                 fold_groups_on_mesh(
                     access.get_chunk, range(n_groups), raw,
                     jit, init_fns[kind], Qa, Qb, mesh=mesh,
