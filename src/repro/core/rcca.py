@@ -36,7 +36,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .linalg import full_f32, orth, sym, topk_svd, tri_solve_right
+from .linalg import full_f32, orth, sym, topk_svd
 from jax.scipy.linalg import solve_triangular
 
 
@@ -438,7 +438,7 @@ def finish(
     La = jnp.linalg.cholesky(sym(Ca + lam_a * QtQa))
     Lb = jnp.linalg.cholesky(sym(Cb + lam_b * QtQb))
     Fw = solve_triangular(La, F, lower=True)  # La⁻¹ F
-    Fw = tri_solve_right(Fw, Lb, trans=True)  # ... Lb⁻ᵀ
+    Fw = solve_triangular(Lb, Fw.T, lower=True).T  # ... Lb⁻ᵀ
     U, S, V = topk_svd(Fw, k)
     sqn = jnp.sqrt(n.astype(Fw.dtype))
     Xa = sqn * (Qa @ solve_triangular(La.T, U, lower=False))  # √n Qa La⁻ᵀ U

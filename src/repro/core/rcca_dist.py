@@ -20,7 +20,8 @@ column buckets that overlap with the next microbatch's compute (XLA
 async collectives) — the distributed-optimization trick from DESIGN.md
 §5.
 
-``orth`` is CholeskyQR2 with k̃×k̃ psum'd Grams (TPU-native; DESIGN §3).
+``orth`` is ``linalg.orth`` (eigh-whitened, then CholeskyQR2) with k̃×k̃
+psum'd Grams (TPU-native; DESIGN §3).
 """
 
 from __future__ import annotations
@@ -33,7 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.kernels.compat import shard_map
 
-from .linalg import full_f32, sym, tri_solve_right
+from .linalg import full_f32, orth, sym
 from .rcca import DEFAULT_ENGINE, RCCAConfig, RCCAResult, finish, resolve_engine
 from repro.exec.engine import pass_schedule
 
@@ -58,21 +59,10 @@ def _trace_psum(tra, trb, row_axes, col_axis):
 
 
 def dist_orth(Y: jax.Array, col_axis: Optional[str]):
-    """Orthonormalize a row-sharded tall matrix: eigh-whitened first
-    round + CholeskyQR cleanup (see linalg.orth); Grams psum over
-    col_axis.  All collectives are k̃×k̃."""
-
-    def gram(M):
-        G = M.astype(jnp.float32).T @ M.astype(jnp.float32)
-        if col_axis is not None:
-            G = _psum(G, col_axis)
-        return sym(G)
-
-    from .linalg import eigh_whiten
-
-    Q = eigh_whiten(Y, gram(Y))
-    L2 = jnp.linalg.cholesky(gram(Q))
-    return tri_solve_right(Q, L2).astype(Y.dtype)
+    """Orthonormalize a row-sharded tall matrix: :func:`linalg.orth`
+    with its Grams psum'd and its scale pmax'd over ``col_axis``.  All
+    collectives are k̃×k̃ or scalars."""
+    return orth(Y, col_axis)
 
 
 # --------------------------------------------------------------------------

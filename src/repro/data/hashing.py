@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
+
 
 def _mix(x: np.ndarray, seed: int) -> np.ndarray:
     """Cheap splitmix64-style integer hash (vectorized, deterministic)."""
@@ -46,11 +48,19 @@ class HashingFeaturizer:
         return out
 
     def featurize_batch(self, token_mat: np.ndarray) -> np.ndarray:
-        """token_mat: (n, L) padded token ids (0 = pad) → (n, n_slots)."""
+        """token_mat: (n, L) padded token ids (0 = pad) → (n, n_slots).
+
+        Under ``RCCA_TRACE`` a ``featurize`` span with the rows, the
+        output's nonzeros (``nnz``) and its bytes."""
         n, L = token_mat.shape
-        out = np.zeros((n, self.n_slots), np.float32)
-        valid = token_mat > 0
-        rows = np.repeat(np.arange(n), L)[valid.ravel()]
-        toks = token_mat.ravel()[valid.ravel()]
-        np.add.at(out, (rows, self.slots(toks)), self.signs(toks))
+        with obs.span("featurize", rows=n, bytes=n * self.n_slots * 4) as sp:
+            out = np.zeros((n, self.n_slots), np.float32)
+            valid = token_mat > 0
+            rows = np.repeat(np.arange(n), L)[valid.ravel()]
+            toks = token_mat.ravel()[valid.ravel()]
+            slots = self.slots(toks)
+            np.add.at(out, (rows, slots), self.signs(toks))
+            if obs.enabled():
+                written = np.unique(rows * self.n_slots + slots)
+                sp.note(nnz=int(np.count_nonzero(out.ravel()[written])))
         return out

@@ -129,6 +129,17 @@ def n_full_chunks(access) -> int:
 # --------------------------------------------------------------------------
 
 
+def on_device(a, b, **span_attrs):
+    """The chunk ``(a, b)`` on the device.  Host (numpy) views are copied
+    explicitly, under an ``h2d`` span with the bytes copied; a chunk
+    already on the device passes through with no copy and no span."""
+    host = [x.nbytes for x in (a, b) if isinstance(x, np.ndarray)]
+    if not host:
+        return a, b
+    with obs.span("h2d", bytes=sum(host), **span_attrs):
+        return jax.device_put((a, b))
+
+
 def run_fold(indexed_chunks, update_fn, acc: SegmentedAccumulator, Qa, Qb, *,
              start_chunk: int = 0, on_chunk=None, span_attrs=None,
              cost_fn=None) -> SegmentedAccumulator:
@@ -149,13 +160,16 @@ def run_fold(indexed_chunks, update_fn, acc: SegmentedAccumulator, Qa, Qb, *,
     ``on_chunk`` callback rides inside it — in-flight bounding IS the
     device-compute wait), stamped with ``span_attrs``; when
     ``cost_fn(a, b)`` is given, its per-kernel cost-model totals are
-    emitted as one ``kernel_cost`` counter at loop end.
-    With tracing off the loop below runs byte-for-byte unchanged.
+    emitted as one ``kernel_cost`` counter at loop end.  A chunk that
+    arrives as host memory is copied to the device before its fold
+    (:func:`on_device`, an ``h2d`` span when traced), in both loops, so
+    tracing changes no work.
     """
     if not obs.enabled():
         for chunk_idx, (a, b) in indexed_chunks:
             if chunk_idx < start_chunk:
                 continue
+            a, b = on_device(a, b)
             acc.update(chunk_idx, update_fn, a, b, Qa, Qb)
             if on_chunk is not None:
                 on_chunk(chunk_idx, acc)
@@ -174,6 +188,7 @@ def run_fold(indexed_chunks, update_fn, acc: SegmentedAccumulator, Qa, Qb, *,
         if chunk_idx < start_chunk:
             continue
         attrs = dict(base, chunk=chunk_idx)
+        a, b = on_device(a, b, **attrs)
         if cost_fn is not None:
             cost = cost_fn(a, b)
             if cost.get("schedule") is not None:

@@ -138,6 +138,10 @@ class _Span:
         self._t0 = monotonic()
         return self
 
+    def note(self, **attrs: Any) -> None:
+        """Add attributes known only once the span's work is done."""
+        self.attrs.update(attrs)
+
     def __exit__(self, *exc: Any) -> None:
         dur = monotonic() - self._t0
         self._note.__exit__(*exc)
@@ -162,6 +166,9 @@ class _NullSpan:
 
     def __enter__(self) -> "_NullSpan":
         return self
+
+    def note(self, **attrs: Any) -> None:
+        return None
 
     def __exit__(self, *exc: Any) -> None:
         return None
