@@ -173,6 +173,8 @@ def hashed_chunks(seed: int, d: int, chunk: int, n_chunks: int):
     """``host(i)`` → chunk i of the hashed paired corpus as two dense
     (chunk, d) f32 numpy arrays: ~30 Zipf(1.3) tokens per row from
     ``synth_paired_docs``, hashed by ``HashingFeaturizer`` into d slots."""
+    import numpy as np
+
     from repro.data import HashingFeaturizer, synth_paired_docs
 
     docs_a, docs_b = synth_paired_docs(chunk * n_chunks, seed=seed)
@@ -181,7 +183,8 @@ def hashed_chunks(seed: int, d: int, chunk: int, n_chunks: int):
 
     def host(i: int):
         rows = slice(i * chunk, (i + 1) * chunk)
-        return ha.featurize_batch(docs_a[rows]), hb.featurize_batch(docs_b[rows])
+        return (np.asarray(ha.featurize_batch(docs_a[rows])),
+                np.asarray(hb.featurize_batch(docs_b[rows])))
 
     return host
 

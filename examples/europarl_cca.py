@@ -4,7 +4,9 @@ Pipeline (paper §4):
   1. paired "sentences" → bag-of-words → feature hashing into d slots
      per view (Weinberger et al. hashing, the paper uses 2^19 slots);
   2. RandomizedCCA (Algorithm 1) over the hashed views, streaming the
-     corpus in row chunks (out-of-core semantics, q+1 data passes);
+     corpus in row chunks (out-of-core semantics, q+1 data passes); each
+     chunk crosses to the device as its token slots and signs
+     (``HashedRows``), and the chunk update builds the dense rows;
   3. report Σρ train/test, feasibility, and the Horst+rcca warm-start
      comparison (paper Table 2b).
 
@@ -26,6 +28,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import HorstConfig, cca_objective, horst_cca
 from repro.core.rcca import RCCAConfig, randomized_cca_iterator
@@ -55,8 +58,10 @@ def main():
     def chunks(lo, hi):
         for s in range(lo, hi, args.chunk):
             e = min(s + args.chunk, hi)
-            yield (jnp.asarray(ha.featurize_batch(docs_a[s:e])),
-                   jnp.asarray(hb.featurize_batch(docs_b[s:e])))
+            yield ha.featurize_batch(docs_a[s:e]), hb.featurize_batch(docs_b[s:e])
+
+    def dense(lo, hi, view):
+        return jnp.asarray(np.concatenate([np.asarray(c[view]) for c in chunks(lo, hi)]))
 
     print(f"[2/3] RandomizedCCA k={args.k} p={args.p} q={args.q} "
           f"({args.q + 1} data passes, streamed)...")
@@ -90,10 +95,8 @@ def main():
     print(f"      done in {time.time()-t0:.1f}s; sum rho = {float(jnp.sum(res.rho)):.4f}")
 
     # evaluate train/test objective on materialized matrices (small scale)
-    A_tr = jnp.concatenate([a for a, _ in chunks(0, n_tr)])
-    B_tr = jnp.concatenate([b for _, b in chunks(0, n_tr)])
-    A_te = jnp.concatenate([a for a, _ in chunks(n_tr, args.n)])
-    B_te = jnp.concatenate([b for _, b in chunks(n_tr, args.n)])
+    A_tr, B_tr = dense(0, n_tr, 0), dense(0, n_tr, 1)
+    A_te, B_te = dense(n_tr, args.n, 0), dense(n_tr, args.n, 1)
     mu_a, mu_b = jnp.mean(A_tr, 0), jnp.mean(B_tr, 0)
     tr = float(cca_objective(A_tr - mu_a, B_tr - mu_b, res.Xa, res.Xb))
     te = float(cca_objective(A_te - mu_a, B_te - mu_b, res.Xa, res.Xb))

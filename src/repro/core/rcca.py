@@ -36,6 +36,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.data.hashing import HashedRows
+
 from .linalg import full_f32, orth, sym, topk_svd
 from jax.scipy.linalg import solve_triangular
 
@@ -536,6 +538,23 @@ def randomized_cca_streaming(
 _jit_cached = functools.lru_cache(maxsize=None)(jax.jit)
 
 
+def _dense(x):
+    return x.to_dense() if isinstance(x, HashedRows) else x
+
+
+@functools.lru_cache(maxsize=None)
+def dense_chunk_rows(update):
+    """``update`` with a :class:`~repro.data.hashing.HashedRows` view of
+    the chunk first turned into its dense rows, inside the same traced
+    program; a dense view passes through untouched, so for dense chunks
+    the program is the unwrapped one.  Keeps ``update``'s name, which
+    names the jitted program.  One wrapper per update function."""
+    @functools.wraps(update)
+    def with_dense_rows(s, a, b, Qa, Qb):
+        return update(s, _dense(a), _dense(b), Qa, Qb)
+    return with_dense_rows
+
+
 def jit_update_fn(kind: str, engine: str):
     """The jitted per-chunk update for one pass flavor — the exact
     function cluster workers and the streaming entries share.  One jit
@@ -547,15 +566,19 @@ def jit_update_fn(kind: str, engine: str):
 def update_fn(kind: str, engine: str):
     """The raw (unjitted) per-chunk update for one pass flavor — what
     the device-parallel group fold scans inside shard_map (jitting is
-    the caller's concern there)."""
+    the caller's concern there).  It takes each chunk view dense or as
+    ``HashedRows`` (:func:`dense_chunk_rows`)."""
     kernels = resolve_engine(engine) == "kernels"
     if kind == "power":
-        return update_power_stats_kernel if kernels else update_power_stats
+        return dense_chunk_rows(update_power_stats_kernel if kernels
+                                else update_power_stats)
     if kind == "final":
-        return update_final_stats_kernel if kernels else update_final_stats
+        return dense_chunk_rows(update_final_stats_kernel if kernels
+                                else update_final_stats)
     raise ValueError(f"unknown pass kind {kind!r}")
 
 
+@functools.lru_cache(maxsize=None)
 def seeded_update_fn(kind: str, kt: int, q_dtype):
     """The raw per-chunk update for a seeded-Ω pass (kernels engine):
     Ω tiles are generated inside the fused Pallas kernels, so the Qa/Qb
@@ -563,7 +586,8 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
     — same arity as :func:`update_fn`'s result, which is what lets the
     fold loop, shard_map specs, cursors and cluster rounds stay
     structurally unchanged.  Bitwise identical to the materialized
-    update fed ``rand.dense_omega(seed, d, kt, q_dtype)``."""
+    update fed ``rand.dense_omega(seed, d, kt, q_dtype)``.  Takes each
+    chunk view dense or as ``HashedRows``, as :func:`update_fn`'s."""
     from repro.kernels import ops as kops
 
     f32 = jnp.float32
@@ -581,7 +605,7 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
                 tr_a=s.tr_a + jnp.sum(a.astype(f32) ** 2),
                 tr_b=s.tr_b + jnp.sum(b.astype(f32) ** 2),
             )
-        return upd
+        return dense_chunk_rows(upd)
     if kind == "final":
         @full_f32
         def upd(s: FinalStats, a, b, seed_a, seed_b) -> FinalStats:
@@ -597,7 +621,7 @@ def seeded_update_fn(kind: str, kt: int, q_dtype):
                 tr_a=s.tr_a + jnp.sum(a.astype(f32) ** 2),
                 tr_b=s.tr_b + jnp.sum(b.astype(f32) ** 2),
             )
-        return upd
+        return dense_chunk_rows(upd)
     raise ValueError(f"unknown pass kind {kind!r}")
 
 

@@ -34,12 +34,14 @@ from __future__ import annotations
 
 import functools
 import inspect
+import math
 from typing import Callable, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro import obs
 from repro.analysis import sanitize
+from repro.data.hashing import HashedRows
 
 import jax
 
@@ -129,14 +131,27 @@ def n_full_chunks(access) -> int:
 # --------------------------------------------------------------------------
 
 
+def _on_host(x) -> bool:
+    if isinstance(x, HashedRows):
+        return isinstance(x.slots, np.ndarray)
+    return isinstance(x, np.ndarray)
+
+
 def on_device(a, b, **span_attrs):
-    """The chunk ``(a, b)`` on the device.  Host (numpy) views are copied
-    explicitly, under an ``h2d`` span with the bytes copied; a chunk
-    already on the device passes through with no copy and no span."""
-    host = [x.nbytes for x in (a, b) if isinstance(x, np.ndarray)]
+    """The chunk ``(a, b)`` on the device.  Host views are copied
+    explicitly: a numpy array as it is, a ``HashedRows`` as its slots and
+    signs (its dense rows are built inside the chunk update).  The copy
+    is an ``h2d`` span with the bytes of the dense rows (``bytes``), the
+    bytes copied (``wire_bytes``) and ``form`` ``"hashed"`` when a host
+    view is ``HashedRows``, else ``"dense"``.  A chunk already on
+    the device passes through with no copy and no span."""
+    host = [x for x in (a, b) if _on_host(x)]
     if not host:
         return a, b
-    with obs.span("h2d", bytes=sum(host), **span_attrs):
+    hashed = any(isinstance(x, HashedRows) for x in host)
+    with obs.span("h2d", bytes=sum(math.prod(x.shape) * x.dtype.itemsize for x in host),
+                  wire_bytes=sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(host)),
+                  form="hashed" if hashed else "dense", **span_attrs):
         return jax.device_put((a, b))
 
 

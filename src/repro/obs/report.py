@@ -23,6 +23,9 @@ Reads every per-process ``trace-*.jsonl`` file and reconstructs:
 * **io overlap** — per prefetch site, the fraction of read time hidden
   behind compute: ``(read_s - io_stall_s) / read_s`` from the ``io``
   counters the prefetcher emits on close.
+* **host→device copies** — per ``form`` of the ``h2d`` spans
+  (``hashed``: slots and signs; ``dense``: the rows), the copies made,
+  the dense rows' bytes they stand for and the bytes actually copied.
 * **merge share** — merge-tree seconds as a fraction of the
   coordinator's fit wall, the scaling number the cluster benchmarks
   track.
@@ -156,6 +159,19 @@ def analyze(path: str) -> Dict[str, Any]:
         for site, v in sorted(io.items())
     }
 
+    # -- host→device copies -------------------------------------------
+    h2d: Dict[str, Dict[str, int]] = {}
+    for spans in by_pid.values():
+        for sp in spans:
+            if sp["name"] == "h2d":
+                a = sp.get("attrs", {})
+                c = h2d.setdefault(str(a.get("form", "dense")),
+                                   {"copies": 0, "bytes": 0, "wire_bytes": 0})
+                c["copies"] += 1
+                c["bytes"] += int(a.get("bytes", 0))
+                c["wire_bytes"] += int(a.get("wire_bytes", a.get("bytes", 0)))
+    report["h2d"] = dict(sorted(h2d.items()))
+
     # -- merge share --------------------------------------------------
     merge_s = fit_s = 0.0
     for spans in by_pid.values():
@@ -254,6 +270,11 @@ def render(report: Dict[str, Any]) -> str:
         out.append(f"  {site:<14} chunks={v['chunks']:<6d} "
                    f"read={v['read_s']:.3f}s stall={v['io_stall_s']:.3f}s "
                    f"overlap={ov}")
+    out.append("")
+    out.append("host→device copies")
+    for form, v in report["h2d"].items():
+        out.append(f"  {form:<14} copies={v['copies']:<6d} "
+                   f"bytes={v['bytes']:d} wire_bytes={v['wire_bytes']:d}")
     m = report["merge"]
     share = f"{m['share']:.1%}" if m["share"] is not None else "-"
     out.append("")
